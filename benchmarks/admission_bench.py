@@ -34,6 +34,7 @@ from repro.traffic import (
     TrafficGateway,
     VirtualClock,
 )
+from repro.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join("experiments", "benchmarks")
 
@@ -182,4 +183,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -81,6 +81,7 @@ from repro.conformance import (
     run_wallclock_case,
 )
 from repro.core.perfmodel.hardware import paper_platform
+from repro.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join("experiments", "benchmarks")
 
@@ -638,4 +639,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mamba_scan.ref import mamba_scan_ref
 from repro.kernels.preemptible_matmul.ref import matmul_ref
 from repro.kernels.rwkv6_scan.ref import rwkv6_scan_ref
+from repro.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, reps=5):
@@ -64,4 +65,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print(run())

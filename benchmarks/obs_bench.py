@@ -52,6 +52,7 @@ from repro.obs import (
 )
 from repro.scheduler.des import simulate_taskset
 from repro.traffic.scenarios import build, get_scenario
+from repro.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join("experiments", "benchmarks")
 
@@ -325,4 +326,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

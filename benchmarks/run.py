@@ -9,6 +9,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -60,4 +62,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

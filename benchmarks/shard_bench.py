@@ -52,6 +52,7 @@ from repro.traffic.scenarios import (
     replicate,
 )
 from repro.traffic.shedding import get_policy
+from repro.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join("experiments", "benchmarks")
 
@@ -303,4 +304,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

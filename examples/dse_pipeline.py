@@ -36,8 +36,8 @@ from repro.pipeline.executor import (
     make_stage_mesh,
     pipeline_backbone,
     reference_backbone,
-    use_mesh,
 )
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -99,7 +99,7 @@ def main():
     mesh = make_stage_mesh(4)
     micro = jax.random.normal(jax.random.PRNGKey(1), (8, 2, 32, 128),
                               jnp.bfloat16)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = pipeline_backbone(small, mesh, 4)(params["blocks"], micro)
     ref = reference_backbone(small, params, micro)
     err = float(jnp.abs(out.astype(jnp.float32)
@@ -109,4 +109,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

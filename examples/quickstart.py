@@ -17,6 +17,7 @@ from repro.core.rt.schedulability import srt_schedulable, stage_utilizations
 from repro.core.workloads import PAPER_WORKLOADS, make_taskset
 from repro.pipeline import PharosServer, design_to_segments
 from repro.scheduler.des import simulate_taskset
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

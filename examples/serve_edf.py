@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.pipeline.serve import PharosServer, ServeTask
+from repro.compile_cache import enable_compile_cache
 
 
 def mk_weights(dims, seed):
@@ -70,4 +71,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -47,6 +47,7 @@ from repro.traffic import (
     get_scenario,
 )
 from repro.traffic.shedding import get_policy
+from repro.compile_cache import enable_compile_cache
 
 
 def run_scenario(
@@ -195,4 +196,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

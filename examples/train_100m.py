@@ -16,6 +16,7 @@ from repro.launch.train import train_loop
 from repro.models.module import param_count
 from repro.models import lm
 import jax
+from repro.compile_cache import enable_compile_cache
 
 
 def build_100m():
@@ -63,4 +64,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -52,6 +52,7 @@ from repro.conformance.harness import (
     Violation,
     WallClockCase,
     WallClockTask,
+    WallTimebase,
     regulate_trace,
     run_case,
     run_conformance,
@@ -62,6 +63,7 @@ from repro.conformance.harness import (
     run_shedding_case,
     run_virtual_server,
     run_wallclock_case,
+    wall_timebase,
 )
 
 __all__ = [
@@ -86,6 +88,7 @@ __all__ = [
     "Violation",
     "WallClockCase",
     "WallClockTask",
+    "WallTimebase",
     "regulate_trace",
     "run_case",
     "run_conformance",
@@ -96,4 +99,5 @@ __all__ = [
     "run_shedding_case",
     "run_virtual_server",
     "run_wallclock_case",
+    "wall_timebase",
 ]

@@ -113,6 +113,17 @@ class CostModel:
                 q[s] = max(q[s], self.window_cost(i, j))
         return q
 
+    def worst_ratio(self, other: "CostModel") -> float:
+        """Largest ``self / other`` segment-cost ratio over the segments
+        ``other`` prices above zero — e.g. measured over modeled WCET,
+        the factor that moves a scenario onto this host's timebase."""
+        return max(
+            self.segment_cost(i, k) / other.segment_cost(i, k)
+            for i in range(other.n_tasks)
+            for k in range(other.n_stages)
+            if other.segment_cost(i, k) > 0.0
+        )
+
     # -- bridges to the other layers ----------------------------------
     def segment_table(self) -> SegmentTable:
         """Analysis view: base = per-stage cost sums, overhead = the
@@ -224,7 +235,10 @@ class CostModel:
         executor (warmup-style probes; min over ``reps`` timed runs
         after one untimed compile pass) and return a wall-clock cost
         model. ``period_scale`` optionally rescales the measured
-        seconds onto another timebase."""
+        seconds onto another timebase. The probes run where the
+        server's windows run (the pallas backend is compiled on a TPU
+        and interpreted on the CPU —
+        `repro.kernels.backend.resolve_interpret`)."""
         import jax
         import jax.numpy as jnp
 

@@ -1834,6 +1834,51 @@ class WallClockCase:
         return not self.violations
 
 
+@dataclass(frozen=True)
+class WallTimebase:
+    """A scenario moved onto the wall timebase of the device its
+    calibration probe ran on (`wall_timebase`)."""
+
+    #: per-(task, layer) window wall times measured on the probe
+    measured: CostModel
+    #: worst measured/modeled (exec-model) segment-cost ratio
+    wcet_ratio: float
+    #: model-seconds -> wall-seconds factor applied to every period
+    period_scale: float
+    #: the probe's serve tasks on the wall timebase (weights shared)
+    tasks: list
+    requests: tuple
+    arrivals: tuple
+
+
+def wall_timebase(
+    built, probe, *, reps: int, headroom: float, seed: int = 0
+) -> WallTimebase:
+    """Calibrate ``probe`` (a `PharosServer` over ``built``'s serve
+    tasks) and move the scenario onto its wall timebase: every period
+    scales by ``headroom`` x the worst measured/modeled segment ratio,
+    so measured utilization is at most modeled utilization / headroom
+    on every stage. The wall tasks are ``probe.tasks`` rescaled with
+    `ServeTask.rescaled`: the weights are built once and shared."""
+    measured = CostModel.calibrate(probe, reps=reps)
+    modeled = CostModel.from_exec_model(
+        built.design, list(built.workloads), probe.tasks,
+        block=probe.block, backend=probe.backend,
+        window_tiles=probe.window_tiles,
+    )
+    ratio = measured.worst_ratio(modeled)
+    scale = headroom * ratio
+    requests, arrivals = built.traffic_bundle(period_scale=scale, seed=seed)
+    return WallTimebase(
+        measured=measured,
+        wcet_ratio=ratio,
+        period_scale=scale,
+        tasks=[t.rescaled(scale) for t in probe.tasks],
+        requests=requests,
+        arrivals=arrivals,
+    )
+
+
 def run_wallclock_case(
     built,
     policy: str = "edf",
@@ -1898,30 +1943,17 @@ def run_wallclock_case(
     scenario = built.scenario.name
 
     # 1. calibrate on the same GEMM geometry the wall run will execute
-    serve_model, _req, _arr = built.serve_bundle(
-        period_scale=1.0, seed=cfg.seed, max_dim=cfg.max_dim
-    )
+    serve_model = built.serve_tasks(max_dim=cfg.max_dim)
     probe = PharosServer(
         serve_model, built.design.n_stages, policy=policy
     )
-    measured = CostModel.calibrate(probe, reps=cfg.wall_reps)
-    modeled = CostModel.from_exec_model(
-        built.design, list(built.workloads), serve_model
+    # 2. wall timebase: headroom x the worst measured/modeled ratio
+    wall = wall_timebase(
+        built, probe,
+        reps=cfg.wall_reps, headroom=cfg.wall_scale_headroom, seed=cfg.seed,
     )
-
-    # 2. wall timebase: scale every period by headroom x the worst
-    # measured/modeled segment ratio, so measured utilization is at
-    # most modeled utilization / headroom on every stage
-    ratio = max(
-        measured.segment_cost(i, k) / modeled.segment_cost(i, k)
-        for i in range(modeled.n_tasks)
-        for k in range(modeled.n_stages)
-        if modeled.segment_cost(i, k) > 0.0
-    )
-    scale = cfg.wall_scale_headroom * ratio
-    serve_tasks, requests, arrivals = built.serve_bundle(
-        period_scale=scale, seed=cfg.seed, max_dim=cfg.max_dim
-    )
+    measured, scale = wall.measured, wall.period_scale
+    serve_tasks, requests, arrivals = wall.tasks, wall.requests, wall.arrivals
     wall_taskset = TaskSet(
         tasks=tuple(
             Task(

@@ -3,7 +3,8 @@
 Each kernel package ships three files:
 
 - ``kernel.py`` — the ``pl.pallas_call`` with explicit BlockSpec VMEM
-  tiling (TPU is the target; validated with ``interpret=True`` on CPU);
+  tiling, compiled on the TPU and interpreted on the CPU
+  (`repro.kernels.backend.resolve_interpret`);
 - ``ops.py``    — the jit'd public wrapper;
 - ``ref.py``    — the pure-jnp oracle the tests sweep against.
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 _LANES = 128
 _NEG_INF = -1e30
@@ -116,7 +116,7 @@ def flash_attention_call(
     block_q: int,
     block_k: int,
     causal: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """q: (B*H, S, hd); k/v: (B*Hkv, S, hd). Returns (B*H, S, hd)."""
     BH, S, hd = q.shape
@@ -153,8 +153,8 @@ def flash_attention_call(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )

@@ -24,7 +24,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Causal GQA attention. q: (B, S, H, hd); k/v: (B, S, Hkv, hd).
 

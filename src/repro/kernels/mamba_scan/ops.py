@@ -13,7 +13,9 @@ def _shrink_to_divisor(chunk: int, extent: int) -> int:
     return max(c, 1)
 
 
-def mamba_scan(dt, B, C, x, A, h0=None, *, chunk: int = DEFAULT_CHUNK, interpret=True):
+def mamba_scan(
+    dt, B, C, x, A, h0=None, *, chunk: int = DEFAULT_CHUNK, interpret=None
+):
     """Selective scan ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t``,
     ``y_t = <h_t, C_t>``. Shapes as in kernel.py; ``h0`` defaults to 0.
     Returns (y, h_final) fp32.

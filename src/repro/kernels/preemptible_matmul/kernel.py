@@ -16,6 +16,10 @@ The overhead this structure pays is exactly Eq. 5's:
     e_store — the fp32 partial tiles are written back to HBM,
     e_load  — resume re-streams the A/B operand tiles (+ partial C).
 
+Arithmetic is fp32 throughout (``Precision.HIGHEST``: the MXU runs its
+multi-pass fp32 contraction), so the compiled kernel, the interpreter
+and a plain ``jnp`` chain agree to fp32 rounding.
+
 Grid: ``(window, k_steps)`` with k minor — each window position owns one
 output tile, revisited across k so the accumulator stays in VMEM for the
 whole K reduction; block index maps use a scalar-prefetch ``start`` so
@@ -30,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 
 def _window_kernel(start_ref, a_ref, b_ref, cin_ref, o_ref, *, k_steps: int):
@@ -43,6 +47,7 @@ def _window_kernel(start_ref, a_ref, b_ref, cin_ref, o_ref, *, k_steps: int):
     o_ref[...] += jnp.dot(
         a_ref[...].astype(jnp.float32),
         b_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -61,7 +66,7 @@ def matmul_window_call(
     window: int,
     n_tiles_n: int,
     k_steps: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Execute output tiles ``[start, start + window)``; returns new c_acc.
 
@@ -95,8 +100,8 @@ def matmul_window_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(c_acc.shape, jnp.float32),
         input_output_aliases={3: 0},  # c_acc (after the scalar operand)
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )

@@ -82,7 +82,7 @@ def matmul_window(
     *,
     block=DEFAULT_BLOCK,
     window_tiles: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Run one window of output tiles starting at flat index ``start``.
 
@@ -117,7 +117,7 @@ def matmul_resumable(
     start_tile: int = 0,
     max_windows: int | None = None,
     c_acc=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Run (part of) ``a @ b`` window by window.
 
@@ -147,7 +147,9 @@ def matmul_resumable(
     return c_acc, MatmulProgress(next_tile=tile, total_tiles=total)
 
 
-def matmul(a, b, *, block=DEFAULT_BLOCK, window_tiles: int = 64, interpret=True):
+def matmul(
+    a, b, *, block=DEFAULT_BLOCK, window_tiles: int = 64, interpret=None
+):
     """Plain full matmul through the preemptible kernel (for testing)."""
     c, prog = matmul_resumable(
         a, b, block=block, window_tiles=window_tiles, interpret=interpret

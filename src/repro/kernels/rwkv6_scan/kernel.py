@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 
 def _wkv_kernel(
@@ -31,12 +31,13 @@ def _wkv_kernel(
     k_ref,
     v_ref,
     w_ref,
-    u_ref,  # (1, hd)
+    u_ref,  # (H, hd) — every head's bonus; row picked per grid step
     y_ref,  # (1, ch, hd) out
     sout_ref,  # (1, hd, hd) out
     s_scr,  # (hd, hd) scratch
     *,
     chunk: int,
+    n_heads: int,
 ):
     ci = pl.program_id(1)
 
@@ -48,7 +49,7 @@ def _wkv_kernel(
     kc = k_ref[0]
     vc = v_ref[0]
     wc = w_ref[0]
-    u = u_ref[0]  # (hd,)
+    u = u_ref[pl.ds(pl.program_id(0) % n_heads, 1), :]  # (1, hd)
 
     logw = jnp.log(wc)  # (ch, hd), negative
     tri_incl = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
@@ -64,19 +65,29 @@ def _wkv_kernel(
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(rows > cols, att, 0.0)  # strict lower triangle
     y_intra = jnp.dot(att, vc, preferred_element_type=jnp.float32)
-    diag = jnp.sum(rc * u[None, :] * kc, axis=-1, keepdims=True)  # (ch, 1)
+    diag = jnp.sum(rc * u * kc, axis=-1, keepdims=True)  # (ch, 1)
     y_ref[0] = y_inter + y_intra + diag * vc
 
-    w_tot = jnp.exp(cumw[-1])  # (hd,)
-    k_scale = kc * jnp.exp(cumw[-1][None, :] - cumw)  # prod_{s>j} w_s
-    s_scr[...] = w_tot[:, None] * S + jnp.dot(
+    last = cumw[chunk - 1 : chunk, :]  # (1, hd)
+    k_scale = kc * jnp.exp(last - cumw)  # prod_{s>j} w_s
+    # the chunk's total decay scales the state's rows: turn the (1, hd)
+    # row into a (hd, 1) column with an identity mask + lane reduction
+    hd = last.shape[1]
+    eye = (
+        jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1)
+    ).astype(jnp.float32)
+    w_tot = jnp.sum(eye * jnp.exp(last), axis=1, keepdims=True)  # (hd, 1)
+    s_scr[...] = w_tot * S + jnp.dot(
         k_scale.T, vc, preferred_element_type=jnp.float32
     )
     sout_ref[0] = s_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("n_heads", "chunk", "interpret"))
-def rwkv6_scan_call(r, k, v, w, u, *, n_heads: int, chunk: int, interpret=True):
+def rwkv6_scan_call(
+    r, k, v, w, u, *, n_heads: int, chunk: int, interpret: bool | None = None
+):
     """r/k/v/w: (B*H, S, hd) fp32; u: (H, hd). Returns (y, S_final)."""
     BH, S, hd = r.shape
     if S % chunk:
@@ -85,14 +96,14 @@ def rwkv6_scan_call(r, k, v, w, u, *, n_heads: int, chunk: int, interpret=True):
 
     grid = (BH, n_chunks)
     call = pl.pallas_call(
-        functools.partial(_wkv_kernel, chunk=chunk),
+        functools.partial(_wkv_kernel, chunk=chunk, n_heads=n_heads),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, hd), lambda bh, c: (bh % n_heads, 0)),
+            pl.BlockSpec((n_heads, hd), lambda bh, c: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, hd), lambda bh, c: (bh, c, 0)),
@@ -103,8 +114,8 @@ def rwkv6_scan_call(r, k, v, w, u, *, n_heads: int, chunk: int, interpret=True):
             jax.ShapeDtypeStruct((BH, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )

@@ -15,7 +15,7 @@ def _shrink_to_divisor(chunk: int, extent: int) -> int:
     return max(c, 1)
 
 
-def rwkv6_scan(r, k, v, w, u, *, chunk: int = DEFAULT_CHUNK, interpret=True):
+def rwkv6_scan(r, k, v, w, u, *, chunk: int = DEFAULT_CHUNK, interpret=None):
     """WKV-6 recurrence over (B, S, H, hd) tensors.
 
     ``S_t = diag(w_t) S_{t-1} + k_t v_t^T``;

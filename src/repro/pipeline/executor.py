@@ -27,43 +27,21 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models import lm
 from repro.models.lm import NO_POLICY
 
-# --- version compatibility: shard_map moved to jax.*, check_rep was
-# renamed check_vma, and set_mesh/use_mesh only exist on newer JAX ---
-if hasattr(jax, "shard_map"):
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` across JAX versions."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # legacy: Mesh itself is a context manager
-
 
 def make_stage_mesh(n_stages: int):
-    return jax.make_mesh((n_stages,), ("stage",))
+    """1-D ``stage`` mesh of ``n_stages`` devices. The axis
+    is `AxisType.Auto`: the pipeline's own sharding lives inside
+    `shard_map`, and the caller may index the stage-stacked result
+    (``outs[-1]``) without naming a sharding for it."""
+    return jax.make_mesh(
+        (n_stages,), ("stage",), axis_types=(AxisType.Auto,)
+    )
 
 
 def _segment_apply(cfg: ArchConfig, params_seg, x, positions):
@@ -133,11 +111,12 @@ def pipeline_backbone(cfg: ArchConfig, mesh, n_stages: int):
         )
         return outs[None]  # leading stage axis for the out_spec
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(P("stage"), P()),
         out_specs=P("stage"),
+        check_vma=False,
     )
 
     def run(blocks_stacked, micro):

@@ -22,8 +22,13 @@ and resumption re-streams the operand tiles (``e_load``) — exactly the
 Eq. 5 cost structure, realized by `kernels.preemptible_matmul`.
 
 Window executors: ``backend="jnp"`` (jitted masked-GEMM windows — fast,
-used by examples/benchmarks) or ``backend="pallas"`` (the real kernel in
-interpret mode — bit-identical semantics, used by the fidelity tests).
+used by examples/benchmarks) or ``backend="pallas"`` (the real kernel:
+compiled on the TPU, interpreted on the CPU — see
+`repro.kernels.backend.resolve_interpret`).
+
+Wall-clock stamps are honest: dispatch is asynchronous, so a layer's
+hand-off or completion is stamped only after its accumulator is ready
+on the device (``block_until_ready``), never at enqueue.
 """
 from __future__ import annotations
 
@@ -31,7 +36,7 @@ import heapq
 import itertools
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import partial
 
 import jax
@@ -95,6 +100,7 @@ def _jnp_row_strip(a, b, c_acc, row, *, bm):
     a_strip = jax.lax.dynamic_slice_in_dim(a, row * bm, bm, 0)
     strip = jnp.dot(
         a_strip.astype(jnp.float32), b.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     return jax.lax.dynamic_update_slice_in_dim(
@@ -145,6 +151,13 @@ class ServeTask:
             raise ValueError("stage map must be non-decreasing (no backtrack)")
         if self.deadline == 0.0:
             object.__setattr__(self, "deadline", self.period)
+
+    def rescaled(self, factor: float) -> "ServeTask":
+        """The same task on another timebase: period and deadline scaled
+        by ``factor``, the weight arrays shared (not copied)."""
+        return replace(
+            self, period=self.period * factor, deadline=self.deadline * factor
+        )
 
 
 class Job:
@@ -549,7 +562,11 @@ class PharosServer:
             st.running = None
             # Completion is stamped off the *injected* clock (the window
             # just executed, so re-read rather than reuse loop-entry
-            # `now`) — keeps all timestamps on one timebase.
+            # `now`) — keeps all timestamps on one timebase — and only
+            # once the layer's accumulator is ready: the dispatch is
+            # asynchronous, so reading the clock first would stamp the
+            # enqueue instead of the device's completion.
+            jax.block_until_ready(job.c_acc)
             self._finish_layer_or_forward(job, self.clock())
         return True
 

@@ -250,11 +250,29 @@ class BuiltScenario:
         ``period_scale`` so every utilization — and therefore every
         admission verdict — is preserved; only the time unit changes.
         ``max_dim`` caps surrogate-GEMM dims for cost-model-driven
-        virtual runs (see `design_to_segments`).
+        virtual runs (see `design_to_segments`). To move weights that
+        already exist onto another timebase, use `ServeTask.rescaled`
+        with `traffic_bundle` instead of materializing them again.
         """
+        serve_tasks = self.serve_tasks(
+            period_scale=period_scale, rows=rows, max_dim=max_dim
+        )
+        return (serve_tasks, *self.traffic_bundle(
+            period_scale=period_scale, seed=seed
+        ))
+
+    def serve_tasks(
+        self,
+        *,
+        period_scale: float = 1.0,
+        rows: int = 128,
+        max_dim: int | None = None,
+    ):
+        """The ``serve_tasks`` of `serve_bundle` alone: the GEMM chains
+        materialized, without building any traffic."""
         from repro.pipeline.stage_split import design_to_segments
 
-        serve_tasks = design_to_segments(
+        return design_to_segments(
             self.design,
             list(self.workloads),
             self.taskset,
@@ -262,6 +280,11 @@ class BuiltScenario:
             period_scale=period_scale,
             max_dim=max_dim,
         )
+
+    def traffic_bundle(self, *, period_scale: float, seed: int = 0):
+        """``(requests, arrivals)`` of `serve_bundle` alone — contracts
+        and seeded traffic on the ``period_scale`` timebase, without
+        materializing any weights."""
         requests = tuple(
             TaskRequest(
                 name=r.name,
@@ -281,7 +304,7 @@ class BuiltScenario:
                 zip(self.scenario.tenants, self._base_periods())
             )
         )
-        return serve_tasks, requests, arrivals
+        return requests, arrivals
 
     def conformance_cost_model(self, serve_tasks, *, period_scale: float = 1.0):
         """The `repro.conformance.CostModel` pricing ``serve_tasks`` on

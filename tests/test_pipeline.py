@@ -156,7 +156,7 @@ _EXECUTOR_SCRIPT = textwrap.dedent(
     from repro.configs.base import ArchConfig
     from repro.models import lm
     from repro.pipeline.executor import (
-        make_stage_mesh, pipeline_backbone, reference_backbone, use_mesh)
+        make_stage_mesh, pipeline_backbone, reference_backbone)
 
     cfg = ArchConfig(name="t", family="dense", n_layers=4, d_model=64,
                      n_heads=4, n_kv_heads=2, d_ff=128, vocab=128)
@@ -164,7 +164,7 @@ _EXECUTOR_SCRIPT = textwrap.dedent(
     mesh = make_stage_mesh(4)
     micro = jax.random.normal(jax.random.PRNGKey(1), (6, 2, 16, 64),
                               jnp.bfloat16)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = pipeline_backbone(cfg, mesh, 4)(params["blocks"], micro)
     ref = reference_backbone(cfg, params, micro)
     err = float(jnp.abs(out.astype(jnp.float32) -

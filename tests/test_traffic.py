@@ -471,29 +471,3 @@ def test_degrade_picks_same_victim_as_shed_but_demotes():
         # same victim selection, different disposition
         assert (shed_v == DROP) == (deg_v == "best_effort")
         assert (shed_v == SUBMIT) == (deg_v == SUBMIT)
-
-
-# ---------------------------------------------------------------------------
-# mini-hypothesis shim: fixtures must coexist with drawn parameters
-# ---------------------------------------------------------------------------
-def _shim_given():
-    """Use the bundled shim explicitly so this holds even when the real
-    hypothesis is installed (CI installs it; the container does not)."""
-    import _mini_hypothesis as mh
-
-    return mh
-
-
-def test_mini_hypothesis_right_aligns_strategies_with_fixture(tmp_path):
-    mh = _shim_given()
-    seen = []
-
-    @mh.settings(max_examples=5)
-    @mh.given(mh.integers(0, 9))
-    def prop(fixture_like, v):
-        seen.append((fixture_like, v))
-
-    prop(tmp_path)  # fixture passed positionally
-    prop(fixture_like=tmp_path)  # and as a keyword, like pytest does
-    assert len(seen) == 10
-    assert all(f == tmp_path and 0 <= v <= 9 for f, v in seen)
