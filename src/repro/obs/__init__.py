@@ -3,7 +3,8 @@ metrics, Perfetto export and trace-level differential diagnosis.
 
 - `TraceRecorder` / `TraceEvent` — one zero-overhead-when-disabled
   event API shared by the DES, the serving runtime and the gateway
-  (`repro.obs.trace`).
+  (`repro.obs.trace`); `TraceRecorder.span` gives the serving runtime
+  host spans (`HostSpan`, `SpanStats`) on the profiler's clock.
 - `MetricsRegistry` (+ `percentile`) — the deadline-compliance metrics
   catalog rolled up from a trace (`repro.obs.metrics`).
 - `to_chrome_trace` / `write_chrome_trace` — Chrome-trace-event JSON,
@@ -30,6 +31,8 @@ from repro.obs.metrics import (
 from repro.obs.trace import (
     EVENT_KINDS,
     LAYERS,
+    HostSpan,
+    SpanStats,
     TraceEvent,
     TraceRecorder,
     to_chrome_trace,
@@ -49,6 +52,8 @@ __all__ = [
     "percentile_summary",
     "EVENT_KINDS",
     "LAYERS",
+    "HostSpan",
+    "SpanStats",
     "TraceEvent",
     "TraceRecorder",
     "to_chrome_trace",

@@ -84,14 +84,25 @@ not None``. A disabled recorder is never even called, so tracing off
 means literally zero events and no per-event work (asserted by
 ``benchmarks/obs_bench.py`` in CI).
 
-The module is dependency-free (stdlib only): every layer can accept a
-recorder without import cycles, and the DES keeps treating it as an
-opaque duck-typed handle.
+Host spans: `TraceRecorder.span` hands a wall-clock serving path a
+reusable `HostSpan` per region it wants timed (`PharosServer` times
+four inside ``step``). Each call of a span opens a
+``jax.profiler.TraceAnnotation`` of its name while a profiler collects,
+so a profiler trace shows it on the host plane on the device
+operations' clock, and adds to the
+span's `SpanStats` (count, total, longest call and when it started) on
+the injected clock, whether a profiler runs or not. Like events, spans
+exist only on an enabled recorder: a layer resolves its span handles
+once, and with tracing off holds None and times nothing.
+
+The module is dependency-free (stdlib only; JAX is imported only when
+a span is made): every layer can accept a recorder without import
+cycles, and the DES keeps treating it as an opaque duck-typed handle.
 """
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 #: every event kind a recorder may carry, in no particular order
 EVENT_KINDS = (
@@ -148,6 +159,68 @@ class TraceEvent:
         return self.attrs.get(key, default)
 
 
+@dataclass(slots=True)
+class SpanStats:
+    """What one named host span measured, on the clock it was made with."""
+
+    count: int = 0
+    total_s: float = 0.0
+    #: the longest call, and the clock reading at its start (None
+    #: before the first call)
+    max_s: float = 0.0
+    t_of_max: float | None = None
+
+
+class HostSpan:
+    """One named host span, entered once per timed call: ``with span:``
+    or `start` / `stop` around a region that is not one block. Calls of
+    one span do not nest. The annotation is made only while a profiler
+    collects (``profiling()``), and the clock is read inside it, so the
+    counters time what the trace shows."""
+
+    __slots__ = (
+        "name", "stats", "_clock", "_annotation", "_profiling", "_open",
+        "_t0",
+    )
+
+    def __init__(
+        self, name: str, clock, stats: SpanStats, annotation, profiling
+    ):
+        self.name = name
+        self.stats = stats
+        self._clock = clock
+        self._annotation = annotation
+        self._profiling = profiling
+        self._open = None
+        self._t0 = 0.0
+
+    def start(self) -> None:
+        if self._profiling():
+            self._open = self._annotation(self.name)
+            self._open.__enter__()
+        self._t0 = self._clock()
+
+    def stop(self) -> None:
+        t0 = self._t0
+        d = self._clock() - t0
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        st = self.stats
+        st.count += 1
+        st.total_s += d
+        if st.t_of_max is None or d > st.max_s:
+            st.max_s = d
+            st.t_of_max = t0
+
+    def __enter__(self) -> "HostSpan":
+        self.start()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
+
+
 class TraceRecorder:
     """Append-only event sink shared by all instrumented layers.
 
@@ -168,7 +241,9 @@ class TraceRecorder:
     `TraceEvent` view lazily on first read.
     """
 
-    __slots__ = ("enabled", "_buf", "_events", "_sticky", "_hot_tag")
+    __slots__ = (
+        "enabled", "_buf", "_events", "_sticky", "_hot_tag", "_spans",
+    )
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
@@ -179,6 +254,7 @@ class TraceRecorder:
         self._events: list[TraceEvent] = []  # lazy materialized view
         self._sticky: dict = {}
         self._hot_tag: tuple[str, int] | None = None  # sink (layer, shard)
+        self._spans: dict[str, SpanStats] = {}
 
     def emit(
         self,
@@ -250,6 +326,30 @@ class TraceRecorder:
             buf_append(row[:5] + (attrs,))
 
         return append
+
+    def span(self, name: str, clock) -> HostSpan | None:
+        """A reusable host span named ``name`` timed on ``clock`` (see
+        the module docstring), or None when disabled. Handles of one
+        name share their counters."""
+        if not self.enabled:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        stats = self._spans.setdefault(name, SpanStats())
+        return HostSpan(
+            name, clock, stats, TraceAnnotation, TraceAnnotation.is_enabled
+        )
+
+    def span_stats(self) -> dict[str, SpanStats]:
+        """A copy of every span's counters, by name."""
+        return {n: replace(self._spans[n]) for n in sorted(self._spans)}
+
+    def clear_spans(self) -> None:
+        """Zero every span's counters; its handles count on from zero
+        (a caller that measures one part of a run clears at its start)."""
+        for n in sorted(self._spans):
+            s = self._spans[n]
+            s.count, s.total_s, s.max_s, s.t_of_max = 0, 0.0, 0.0, None
 
     def annotate(self, **kv) -> None:
         """Merge sticky attributes into every future event."""

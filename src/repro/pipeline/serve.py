@@ -51,6 +51,16 @@ from repro.kernels.preemptible_matmul import (
 
 DEFAULT_BLOCK = (128, 128, 128)
 
+#: host spans of the wall-clock serving path (`TraceRecorder.span`),
+#: disjoint: the EDF preemption check, pool pop and dispatch event; a
+#: layer's accumulator set-up (`_start_layer`); one window's geometry
+#: and jit dispatch (`_exec_window`); the wait for a finished layer's
+#: accumulator
+SPAN_SCHEDULE = "pharos.schedule"
+SPAN_ACC_INIT = "pharos.acc_init"
+SPAN_WINDOW_LAUNCH = "pharos.window_launch"
+SPAN_LAYER_SYNC = "pharos.layer_sync"
+
 #: Degenerate safety tick (seconds): the smallest forced clock advance
 #: of a serving loop iteration that made no progress — no window ran
 #: and the next modeled event is not in the future (a float-equality
@@ -323,7 +333,9 @@ class PharosServer:
     accumulators resident, nothing spills) / segment_end / complete /
     deadline_miss — stamped on the injected clock; ``trace_shard`` tags
     every event with the shard index when the server backs one
-    `ShardedGateway` replica. None (the default) emits nothing.
+    `ShardedGateway` replica. None (the default) emits nothing. Without
+    a ``cost_model`` the recorder also times the four ``SPAN_*`` host
+    spans of every served window (`TraceRecorder.span`).
     """
 
     def __init__(
@@ -391,6 +403,23 @@ class PharosServer:
             else None
         )
         self._tr_shard = trace_shard
+        # host spans of the wall-clock path, resolved once like `_tr`;
+        # the cost-model path times nothing
+        spans = (
+            [
+                self._tr.span(name, self.clock)
+                for name in (
+                    SPAN_SCHEDULE, SPAN_ACC_INIT,
+                    SPAN_WINDOW_LAUNCH, SPAN_LAYER_SYNC,
+                )
+            ]
+            if self._tr is not None and cost_model is None
+            else [None] * 4
+        )
+        (
+            self._sp_schedule, self._sp_acc_init,
+            self._sp_launch, self._sp_sync,
+        ) = spans
         self._missed_in_flight: set[int] = set()
         self.released_per_task = [0] * len(tasks)
         self.completed_per_task = [0] * len(tasks)
@@ -415,11 +444,16 @@ class PharosServer:
 
     # ------------------------------------------------------------------
     def _start_layer(self, job: Job) -> None:
+        sp = self._sp_acc_init
+        if sp is not None:
+            sp.start()
         t = self.tasks[job.task_id]
         w = t.weights[job.layer]
         M, N = job.x.shape[0], w.shape[1]
         job.c_acc = jnp.zeros((M, N), jnp.float32)
         job.next_tile = 0
+        if sp is not None:
+            sp.stop()
 
     def _layer_tiles(self, job: Job) -> int:
         t = self.tasks[job.task_id]
@@ -548,16 +582,26 @@ class PharosServer:
 
     def _step_stage(self, st: StageRuntime, now: float) -> bool:
         """Run one tile window on stage ``st``. Returns True if it ran."""
+        sched, launch, sync = self._sp_schedule, self._sp_launch, self._sp_sync
+        if sched is not None:
+            sched.start()
         self._preempt_if_due(st, now)
         if st.running is None:
             st.running = st.pop()
-            if st.running is None:
-                return False
-            self._emit_dispatch(st, now)
-            if st.running.c_acc is None:
-                self._start_layer(st.running)
+            if st.running is not None:
+                self._emit_dispatch(st, now)
+        if sched is not None:
+            sched.stop()
         job = st.running
+        if job is None:
+            return False
+        if job.c_acc is None:  # popped at the start of a layer
+            self._start_layer(job)
+        if launch is not None:
+            launch.start()
         total = self._exec_window(job)
+        if launch is not None:
+            launch.stop()
         if job.next_tile >= total:
             st.running = None
             # Completion is stamped off the *injected* clock (the window
@@ -566,7 +610,11 @@ class PharosServer:
             # once the layer's accumulator is ready: the dispatch is
             # asynchronous, so reading the clock first would stamp the
             # enqueue instead of the device's completion.
+            if sync is not None:
+                sync.start()
             jax.block_until_ready(job.c_acc)
+            if sync is not None:
+                sync.stop()
             self._finish_layer_or_forward(job, self.clock())
         return True
 
@@ -694,6 +742,16 @@ class PharosServer:
                 )
                 jax.block_until_ready(c)
                 x = c  # chain shapes like the real execution
+
+    def host_span_stats(self) -> dict:
+        """A copy of the counters of the ``SPAN_*`` host spans, by name
+        (`TraceRecorder.span_stats`); empty where the server times none."""
+        return {} if self._sp_launch is None else self._tr.span_stats()
+
+    def clear_host_spans(self) -> None:
+        """Zero the ``SPAN_*`` counters (`TraceRecorder.clear_spans`)."""
+        if self._sp_launch is not None:
+            self._tr.clear_spans()
 
     def finalize_report(self, now: float | None = None) -> ServerReport:
         """Horizon-end accounting: expose per-task in-flight counts and

@@ -93,6 +93,10 @@ class GatewayReport:
     mode_switches: list[tuple[float, str, tuple[str, ...]]] = field(
         default_factory=list
     )
+    #: the server's host span counters over the run's horizon,
+    #: ``name -> SpanStats`` (`PharosServer.host_span_stats`; empty
+    #: where it times none)
+    host_spans: dict = field(default_factory=dict)
 
     def tenant(self, name: str) -> TenantStats:
         for t in self.tenants:
@@ -126,6 +130,9 @@ class _RunState:
     virtual: bool
     cost_driven: bool
     virtual_dt: float
+    #: the server's host span counters as the horizon was reached
+    #: (None before)
+    host_spans: dict | None = None
 
 
 class TrafficGateway:
@@ -353,6 +360,11 @@ class TrafficGateway:
             virtual_dt = p_min / 20.0
         if warmup:
             self.server.warmup()
+        # the server's host spans count from here: warm-up and earlier
+        # runs are not this run's
+        clear = getattr(self.server, "clear_host_spans", None)
+        if clear is not None:
+            clear()
         self._run = _RunState(
             horizon_s=horizon_s,
             stats=stats,
@@ -382,6 +394,8 @@ class TrafficGateway:
         scalar one decision-for-decision."""
         st = self._require_run()
         rel = self.clock.now() - st.t0
+        if rel >= st.horizon_s and st.host_spans is None:
+            st.host_spans = self._host_span_stats()
         end = st.pos
         n = len(st.sched)
         while end < n and (
@@ -437,7 +451,15 @@ class TrafficGateway:
             decisions=list(self.admission.decisions),
             server_report=self.server.finalize_report(self.clock.now()),
             mode_switches=list(self.mode_switches),
+            host_spans=(
+                st.host_spans if st.host_spans is not None
+                else self._host_span_stats()
+            ),
         )
+
+    def _host_span_stats(self) -> dict:
+        stats = getattr(self.server, "host_span_stats", None)
+        return {} if stats is None else stats()
 
     def run(
         self,
