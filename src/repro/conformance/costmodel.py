@@ -232,7 +232,8 @@ class CostModel:
         cls, server, *, reps: int = 3, period_scale: float = 1.0
     ) -> "CostModel":
         """Measure per-(task, layer) window wall times on ``server``'s
-        executor (warmup-style probes; min over ``reps`` timed runs
+        executor (warmup-style probes through `_run_window`, the launch
+        the server makes; min over ``reps`` timed runs
         after one untimed compile pass) and return a wall-clock cost
         model. ``period_scale`` optionally rescales the measured
         seconds onto another timebase. The probes run where the

@@ -15,8 +15,8 @@ Event vocabulary (``TraceEvent.kind``):
 - ``release``        — a job entered the system (DES release, runtime
                        ``PharosServer.submit``, gateway submit path).
 - ``dispatch``       — a stage server started (or resumed) serving a
-                       job; ``attrs["resumed"]`` marks a
-                       post-preemption resume.
+                       job; ``attrs["resumed"]`` marks a resume in
+                       the middle of a layer, after a preemption.
 - ``preempt_store``  — a running job was preempted at a window
                        boundary; ``attrs["xi"]`` is the store-side
                        charge serialized before the preemptor starts

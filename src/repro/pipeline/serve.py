@@ -29,6 +29,12 @@ compiled on the TPU, interpreted on the CPU — see
 Wall-clock stamps are honest: dispatch is asynchronous, so a layer's
 hand-off or completion is stamped only after its accumulator is ready
 on the device (``block_until_ready``), never at enqueue.
+
+Launch: every window is one jitted dispatch whose operands already live
+on the device (`LaunchPlan`: the start scalars are made once per layer
+geometry, and a layer's first window reads the geometry's zero
+accumulator), so after `PharosServer.warmup` a serving step transfers
+nothing from the host.
 """
 from __future__ import annotations
 
@@ -36,26 +42,24 @@ import heapq
 import itertools
 import time
 from collections import deque
+from collections.abc import Callable
 from dataclasses import dataclass, field, replace
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.preemptible_matmul import (
-    grid_geometry,
-    matmul_window,
-    pick_window,
-)
+from repro.kernels.preemptible_matmul import grid_geometry, pick_window
+from repro.kernels.preemptible_matmul.kernel import matmul_window_call
 
 DEFAULT_BLOCK = (128, 128, 128)
 
 #: host spans of the wall-clock serving path (`TraceRecorder.span`),
 #: disjoint: the EDF preemption check, pool pop and dispatch event; a
-#: layer's accumulator set-up (`_start_layer`); one window's geometry
-#: and jit dispatch (`_exec_window`); the wait for a finished layer's
-#: accumulator
+#: layer's progress-table reset (`_start_layer`); one window's plan
+#: lookup and jit dispatch (`_exec_window`); the wait for a finished
+#: layer's accumulator
 SPAN_SCHEDULE = "pharos.schedule"
 SPAN_ACC_INIT = "pharos.acc_init"
 SPAN_WINDOW_LAUNCH = "pharos.window_launch"
@@ -77,8 +81,9 @@ def window_plan(
 ) -> tuple[int, int]:
     """(window size, window count) the executor runs for one
     ``(M,K) @ (K,N)`` layer — the single source of truth for window
-    geometry, shared by `_window_for`, the cost-model validation in
-    `PharosServer.__init__` and `repro.conformance.CostModel`. The jnp
+    geometry, shared by the server's launch plans, the cost-model
+    validation in `PharosServer.__init__` and
+    `repro.conformance.CostModel`. The jnp
     backend serves one output-tile row per window (exact-FLOP fast
     path); the pallas backend honours the configured tile count."""
     _, n_n, _, total = grid_geometry(M, N, K, block)
@@ -119,22 +124,72 @@ def _jnp_row_strip(a, b, c_acc, row, *, bm):
     )
 
 
-def _run_window(a, b, c_acc, start, *, block, window, backend):
-    M, K = a.shape
-    _, N = b.shape
-    n_m, n_n, _, total = grid_geometry(M, N, K, block)
+@dataclass(frozen=True)
+class LaunchPlan:
+    """How every tile window of one layer geometry is launched: one
+    jitted dispatch whose operands all live on the device.
+
+    Window ``i`` starts at tile ``i * window`` (`pick_window` divides
+    the tile count, and the scheduler preempts only at window
+    boundaries), and hands the executable ``starts[i]``, an int32
+    device scalar made once with the plan: its first tile, or its tile
+    row on the jnp row-strip path."""
+
+    total: int  # output tiles of the layer
+    window: int  # tiles a window
+    starts: tuple[jax.Array, ...]
+    #: ``(a, b, c_acc, start scalar) -> c_acc'``, one jitted dispatch
+    launch: Callable
+
+
+@cache
+def launch_plan(M, K, N, block, backend, window) -> LaunchPlan:
+    """The plan of an ``(M,K) @ (K,N)`` layer run ``window`` tiles at a
+    time (the pallas backend takes the largest divisor of the tile count
+    up to ``window``, `pick_window`). Kept for the process: a plan holds
+    a few bytes of device scalars, shared by every server of the
+    geometry."""
+    n_m, n_n, k_steps, total = grid_geometry(M, N, K, block)
     if backend == "pallas":
-        return matmul_window(
-            a, b, c_acc, start, block=block, window_tiles=window
+        window = pick_window(total, window)
+        starts = range(0, total, window)
+        kernel = partial(
+            matmul_window_call,
+            block=block, window=window, n_tiles_n=n_n, k_steps=k_steps,
         )
-    if window == n_n and start % n_n == 0:
-        c = _jnp_row_strip(a, b, c_acc, jnp.int32(start // n_n), bm=block[0])
+
+        def launch(a, b, c_acc, start):
+            return kernel(start, a, b, c_acc)
+    elif window == n_n:
+        starts = range(n_m)  # the row strip takes its tile row
+        launch = partial(_jnp_row_strip, bm=block[0])
     else:
-        c = _jnp_window(
-            a, b, c_acc, jnp.int32(start),
+        starts = range(0, total, window)
+        launch = partial(
+            _jnp_window,
             n_tiles_m=n_m, n_tiles_n=n_n, block=block, window=window,
         )
-    return c, min(start + window, total)
+    return LaunchPlan(
+        total=total,
+        window=window,
+        starts=tuple(jax.device_put([np.int32(s) for s in starts])),
+        launch=launch,
+    )
+
+
+def _run_window(a, b, c_acc, start, *, block, window, backend):
+    """Run the window of ``a @ b`` that starts at tile ``start`` (a
+    multiple of the window) into ``c_acc``; returns ``(c_acc',
+    next_tile)``. One jitted dispatch through the geometry's
+    `launch_plan`: no operand comes from the host."""
+    plan = launch_plan(a.shape[0], a.shape[1], b.shape[1], block, backend, window)
+    i, off = divmod(start, plan.window)
+    if off:
+        raise ValueError(
+            f"window start {start} is not a multiple of {plan.window}"
+        )
+    c = plan.launch(a, b, c_acc, plan.starts[i])
+    return c, min(start + plan.window, plan.total)
 
 
 # ---------------------------------------------------------------------------
@@ -272,6 +327,10 @@ class ServerReport:
     jobs_completed: int
     jobs_released: int
     windows_executed: int
+    #: layer geometries the server first met while serving, whose launch
+    #: plan and zero accumulator it built then (a geometry `warmup` did
+    #: not see): 0 when warm-up covered every layer served
+    launch_plan_misses: int = 0
     #: released-but-unfinished jobs per task at the last
     #: `PharosServer.finalize_report` — the same number the gateway's
     #: backlog monitor polls via `pending`, so overload verdicts and
@@ -424,6 +483,10 @@ class PharosServer:
         self.released_per_task = [0] * len(tasks)
         self.completed_per_task = [0] * len(tasks)
         self.stages = [StageRuntime(k, policy) for k in range(n_stages)]
+        #: ``(M, K, N) -> (LaunchPlan, zero accumulator)`` of every layer
+        #: geometry the server runs; the zeros are each first window's
+        #: ``c_acc``, never written (the windows do not donate it)
+        self._plans: dict[tuple[int, int, int], tuple] = {}
         key = jax.random.PRNGKey(seed)
         self.inputs = []
         for t in tasks:
@@ -444,35 +507,42 @@ class PharosServer:
 
     # ------------------------------------------------------------------
     def _start_layer(self, job: Job) -> None:
+        """Enter the job's current layer: reset its progress-table row.
+        ``c_acc`` stays None (nothing issued in this layer yet): the
+        layer's first window makes the accumulator."""
         sp = self._sp_acc_init
         if sp is not None:
             sp.start()
-        t = self.tasks[job.task_id]
-        w = t.weights[job.layer]
-        M, N = job.x.shape[0], w.shape[1]
-        job.c_acc = jnp.zeros((M, N), jnp.float32)
         job.next_tile = 0
         if sp is not None:
             sp.stop()
 
-    def _layer_tiles(self, job: Job) -> int:
-        t = self.tasks[job.task_id]
-        w = t.weights[job.layer]
-        M, K = job.x.shape
-        _, _, _, total = grid_geometry(M, w.shape[1], K, self.block)
-        return total
-
-    def _window_for(self, job: Job) -> int:
-        """Preemption quantum of the current layer (see `window_plan`)."""
-        t = self.tasks[job.task_id]
-        w = t.weights[job.layer]
-        M, K = job.x.shape
+    def _new_plan(self, M: int, K: int, N: int) -> tuple:
+        """Build, keep and return the ``(LaunchPlan, zeros)`` of a layer
+        geometry (its window from `window_plan`)."""
         window, _ = window_plan(
-            M, w.shape[1], K,
+            M, N, K,
             block=self.block, backend=self.backend,
             window_tiles=self.window_tiles,
         )
-        return window
+        entry = (
+            launch_plan(M, K, N, self.block, self.backend, window),
+            jnp.zeros((M, N), jnp.float32),
+        )
+        self._plans[(M, K, N)] = entry
+        return entry
+
+    def _plan(self, job: Job) -> tuple:
+        """``(LaunchPlan, zeros)`` of the job's current layer; a
+        geometry warm-up did not see is planned now and counted in
+        ``report.launch_plan_misses``."""
+        M, K = job.x.shape
+        N = self.tasks[job.task_id].weights[job.layer].shape[1]
+        entry = self._plans.get((M, K, N))
+        if entry is None:
+            self.report.launch_plan_misses += 1
+            entry = self._new_plan(M, K, N)
+        return entry
 
     def _finish_layer_or_forward(self, job: Job, now: float) -> None:
         """Layer done: advance; forward to next stage / complete job."""
@@ -557,28 +627,25 @@ class PharosServer:
             "dispatch", now, "runtime",
             self.tasks[job.task_id].name,
             st.idx, self._tr_shard, release=job.release,
-            # c_acc still set => mid-layer resume after a preemption
-            attrs={"resumed": True} if job.c_acc is not None else None,
+            # past the layer's first tile => mid-layer resume
+            attrs={"resumed": True} if job.next_tile > 0 else None,
         )
 
     def _exec_window(self, job: Job) -> int:
         """Execute one tile window of ``job``'s current layer; returns
         the layer's total tile count."""
-        t = self.tasks[job.task_id]
-        w = t.weights[job.layer]
-        total = self._layer_tiles(job)
-        window = self._window_for(job)
+        plan, zeros = self._plan(job)
         job.c_acc, job.next_tile = _run_window(
             job.x,
-            w,
-            job.c_acc,
+            self.tasks[job.task_id].weights[job.layer],
+            zeros if job.c_acc is None else job.c_acc,
             job.next_tile,
             block=self.block,
-            window=window,
+            window=plan.window,
             backend=self.backend,
         )
         self.report.windows_executed += 1
-        return total
+        return plan.total
 
     def _step_stage(self, st: StageRuntime, now: float) -> bool:
         """Run one tile window on stage ``st``. Returns True if it ran."""
@@ -595,7 +662,7 @@ class PharosServer:
         job = st.running
         if job is None:
             return False
-        if job.c_acc is None:  # popped at the start of a layer
+        if job.c_acc is None and job.layer == 0:  # a fresh job
             self._start_layer(job)
         if launch is not None:
             launch.start()
@@ -626,7 +693,7 @@ class PharosServer:
         if job is not None:
             if now < st.busy_until - 1e-18:
                 return False  # mid-window in virtual time
-            if job.next_tile >= self._layer_tiles(job):
+            if job.next_tile >= self._plan(job)[0].total:
                 st.running = None
                 self._finish_layer_or_forward(job, st.busy_until)
                 # a same-stage next layer re-occupies `running`; a
@@ -723,22 +790,20 @@ class PharosServer:
 
     # ------------------------------------------------------------------
     def warmup(self) -> None:
-        """Pre-compile every (layer geometry x window) the run will use —
+        """Plan and pre-compile every layer geometry the run will use —
         JIT stalls inside the serving loop would otherwise blow every
-        deadline in the first hyperperiod."""
+        deadline in the first hyperperiod, and planning there would
+        transfer start scalars from the host."""
         for i, t in enumerate(self.tasks):
             x = self.inputs[i]
             for w in t.weights:
-                M, N = x.shape[0], w.shape[1]
-                window, _ = window_plan(
-                    M, N, x.shape[1],
-                    block=self.block, backend=self.backend,
-                    window_tiles=self.window_tiles,
+                (M, K), N = x.shape, w.shape[1]
+                plan, zeros = self._plans.get((M, K, N)) or self._new_plan(
+                    M, K, N
                 )
-                c = jnp.zeros((M, N), jnp.float32)
                 c, _ = _run_window(
-                    x, w, c, 0,
-                    block=self.block, window=window, backend=self.backend,
+                    x, w, zeros, 0,
+                    block=self.block, window=plan.window, backend=self.backend,
                 )
                 jax.block_until_ready(c)
                 x = c  # chain shapes like the real execution

@@ -67,9 +67,9 @@ def test_smoke_correctness_phase_fails_a_bf16_contraction(
         serve_model, built.design.n_stages,
         policy=built.scenario.policy, backend="pallas",
     )
-    window = serve.matmul_window
+    window = serve._run_window
     monkeypatch.setattr(
-        serve, "matmul_window",
+        serve, "_run_window",
         lambda a, b, *args, **kw: window(
             a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), *args, **kw
         ),
