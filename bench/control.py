@@ -3,16 +3,16 @@
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3
 
-For each seed it builds the cell's deployment as a run does (weights
-and inputs from the seed, at full width) and computes each tenant's
-chain twice: with the reference (fp32 at HIGHEST) and with the control
-(the reference one precision down: bf16 in three passes). It prints the
-control's ``rel_err`` per tenant beside the cell's limit, one JSON line
-per seed, with the chain at ``Precision.HIGH`` beside it as a witness
-of the emulated control. Every served job of a tenant reads the same input, so one
-chain per tenant stands for every job a run compares. The limits were
-set between the sound runs' largest reading and the control's smallest
-(PERF.md).
+For each seed it builds the cell's deployment as a run does (each
+tenant through its model, from the seed, at full width) and, for each
+tenant and each of the ordinals `ORDINALS`, computes the model's
+``reference(k)`` and ``control(k)`` (the reference one precision
+down). It prints the control's ``rel_err`` per tenant and ordinal
+beside the cell's limit, one JSON line per seed, and the model's
+``witness(k)`` beside it where the model has one (the GEMM chain's:
+the chain at ``Precision.HIGH``, a witness of the emulated control).
+The limits were set between the sound runs' largest reading and the
+control's smallest (PERF.md).
 """
 from __future__ import annotations
 
@@ -22,6 +22,8 @@ import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
+#: the job ordinals whose control each tenant reads
+ORDINALS = (0, 1, 2)
 
 
 def main(argv=None) -> int:
@@ -41,14 +43,15 @@ def main(argv=None) -> int:
     for seed in (int(x) for x in args.seeds.split(",")):
         dep = harness.build_deployment(config, seed)
         row = {"seed": seed}
-        for name, x, ws in zip(dep.names, dep.inputs, dep.weights):
-            ref = reference.chain(x, ws)
-            err = reference.max_rel_err([reference.chain_bf16x3(x, ws)], ref)
-            high = reference.max_rel_err([reference.chain_high(x, ws)], ref)
-            row[name] = {
-                "control": err, "precision_high": high,
-                "limit": cell["limits"]["rel_err"][name],
-            }
+        for name, t in zip(dep.names, dep.tenants):
+            got = {"control": [], "limit": cell["limits"]["rel_err"][name]}
+            for k in ORDINALS:
+                ref = t.reference(k)
+                got["control"].append(reference.max_rel_err([t.control(k)], ref))
+                if hasattr(t, "witness"):
+                    got.setdefault("witness", []).append(
+                        reference.max_rel_err([t.witness(k)], ref))
+            row[name] = got
         print(json.dumps(row), flush=True)
         del dep
     return 0

@@ -3,12 +3,14 @@
 A cell (``workloads/<cell>.json``) names a configuration
 (``configs/<config>.json``: the deployment's tenants, platform and
 sizes), a scheduling policy and each tenant's traffic in absolute
-seconds. `run_cell` then:
+seconds. Each tenant of the configuration is served as its model
+(``"model": "<name>"``, ``models/<name>.py``; `DEFAULT_MODEL` where it
+names none), which builds, counts and checks it (`load_model`).
+`run_cell` then:
 
 1. builds the deployment: the DSE design of the configuration's
-   tenants (`repro.traffic.scenarios.build`), and the tenants' GEMM
-   chains at full width with weights and inputs made on the device
-   from the seed, in one jitted call each;
+   tenants (`repro.traffic.scenarios.build`), and each tenant at full
+   width through its model, from the seed, on the device;
 2. sets up as a user would: `PharosServer.warmup`,
    `CostModel.calibrate`, and the gateway's admission on the
    calibrated WCETs (all of it counts in ``setup_s``);
@@ -16,9 +18,10 @@ seconds. `run_cell` then:
    `release_due`, `PharosServer.step` and `finish_run`, on a
    `WallClock`; open-loop tenants are released by the gateway on the
    cell's schedule, closed-loop tenants are submitted as best-effort
-   jobs when their previous job completes;
+   jobs when their previous job completes; each job reads the input
+   its model gives its ordinal (its place among the tenant's jobs);
 4. waits for every job due in the window, then compares the served
-   outputs with the plain reference (`reference.chain`);
+   outputs with the model's plain reference of their ordinals;
 5. returns the result line of the benchmark's contract.
 
 With ``trace`` it also records the server's schedule events and a
@@ -37,14 +40,12 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from pathlib import Path
 from types import SimpleNamespace
 
 import jax
-import jax.numpy as jnp
 
-import counts
+import program_spans
 import reference
 import trace_reduce
 import traffic
@@ -59,8 +60,10 @@ SPANS = (
     "client.submit",
     "client.idle",
 )
-#: device operations of the window executor (the Pallas kernel's jit)
-KERNEL = "matmul_window"
+#: where `load_model` looks for ``<name>.py``, in this order
+MODEL_DIRS = [BENCH / "models"]
+#: the model of a tenant that names none
+DEFAULT_MODEL = "gemm_chain"
 #: a finished job's output is kept for the check when it is this small;
 #: larger ones are sampled
 SMALL_OUTPUT_BYTES = 1 << 20
@@ -108,6 +111,61 @@ def load_reader(metric: str):
     return mod.read
 
 
+def load_model(name: str):
+    """The model module ``<name>.py`` of the first of `MODEL_DIRS` that
+    holds one, loaded once per process (a model module may build on
+    another through this). Its contract:
+
+    ``build(specs, *, key, rows, block, stages, workloads, max_dim)``
+        returns one tenant object per entry of ``specs``, the
+        configuration's tenants that name this model, in its order.
+        ``key`` is the seed's PRNG key, the same for every model of a
+        configuration (a model draws from keys it folds from it);
+        ``rows`` the configuration's rows; ``block`` the server's tile
+        block; ``stages`` per tenant the stage of each layer of its DSE
+        workload; ``workloads`` per tenant that workload
+        (`repro.traffic.scenarios.BuiltScenario.workloads`);
+        ``max_dim`` a width cap for CPU tests (None on the chip). What
+        the reference needs is made on the device here.
+
+    A tenant object has:
+
+    - ``layers``: the layers of a job (progress ``(layers, 0)`` is done);
+    - ``output_bytes``: the bytes of a job's output, for the keep rule;
+    - ``task(name, period, deadline)``: the program's task object;
+    - ``job_input(k)``: the input of the tenant's ``k``-th job (from 0);
+    - ``output(job)``: the array a finished job produced;
+    - ``reference(k)``: what job ``k`` must produce, computed plainly;
+    - ``control(k)``: the same one precision down (`control.py`), and
+      optionally ``witness(k)``, another path to it that `control.py`
+      prints beside it;
+    - ``flops_done(progress)``: FLOPs a job has completed at progress
+      ``(layer, next_tile)``;
+    - ``windows_between(window_tiles, a, b)``: ``(kernel, flops, bytes,
+      gemm)`` of each window a job ran from progress ``a`` to ``b``,
+      ``gemm`` the ``(M, K, N, start, tiles)`` of a window of the GEMM
+      window kernel (None for another kernel).
+    """
+    if not name or not all(c.isalnum() or c in "_.-" for c in name):
+        raise ValueError(f"bad model name {name!r}")
+    key = "bench_model_" + name.replace(".", "_").replace("-", "_")
+    if key in sys.modules:
+        return sys.modules[key]
+    path = next((d / f"{name}.py" for d in MODEL_DIRS
+                 if (d / f"{name}.py").is_file()), None)
+    if path is None:
+        raise FileNotFoundError(f"no model file {name}.py in {MODEL_DIRS}")
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[key] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[key]
+        raise
+    return mod
+
+
 def percentile(values, q: float) -> float:
     """Nearest-rank percentile (copied from `repro.obs.metrics`)."""
     vals = sorted(values)
@@ -124,7 +182,7 @@ def seed_key(seed: int):
 
 
 # ---------------------------------------------------------------------------
-# the deployment: design, shapes, weights and inputs
+# the deployment: design and tenants
 # ---------------------------------------------------------------------------
 @dataclass
 class Deployment:
@@ -132,47 +190,9 @@ class Deployment:
     built: object  # repro.traffic.scenarios.BuiltScenario
     names: list[str]
     criticality: list[str]
-    #: per tenant, per layer: ``(M, K, N)``
-    shapes: list[list[tuple[int, int, int]]]
-    stage_of_layer: list[tuple[int, ...]]
-    weights: list[tuple]
-    inputs: list
+    #: per tenant, the tenant object its model built (`load_model`)
+    tenants: list
     block: tuple[int, int, int]
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def chain_shapes(workload, rows: int, block, max_dim=None):
-    """The served GEMM chain of one workload: each layer's N rounded up
-    to the block, its K the previous layer's N (the first layer's own
-    K), M the input rows; ``max_dim`` caps K and N (CPU tests only).
-    Copied from `repro.pipeline.stage_split.design_to_segments`."""
-    bm, bk, bn = block
-    cap = None if max_dim is None else _round_up(max_dim, max(bk, bn))
-    M = _round_up(rows, bm)
-    k = _round_up(workload.layers[0].K, bk)
-    out = []
-    for layer in workload.layers:
-        n = _round_up(layer.N, bn)
-        if cap is not None:
-            k, n = min(k, cap), min(n, cap)
-        out.append((M, k, n))
-        k = n
-    return out
-
-
-@partial(jax.jit, static_argnums=0)
-def _normal_leaves(shapes, key):
-    """One array per shape, standard normal over sqrt(rows) for a
-    weight (``scale`` flag 1) or plain for an input."""
-    keys = jax.random.split(key, len(shapes))
-    return tuple(
-        jax.random.normal(k, s, jnp.float32)
-        / (jnp.sqrt(jnp.float32(s[0])) if scale else 1.0)
-        for k, (*s, scale) in zip(keys, shapes)
-    )
 
 
 def build_deployment(config: dict, seed: int, *, max_dim=None,
@@ -209,37 +229,32 @@ def build_deployment(config: dict, seed: int, *, max_dim=None,
     from repro.pipeline.serve import DEFAULT_BLOCK
 
     block = tuple(DEFAULT_BLOCK)
-    shapes = [
-        chain_shapes(w, config["rows"], block, max_dim)
-        for w in built.workloads
-    ]
-    stage_of_layer = []
+    stages = []
     for i in range(len(tenants)):
-        stages = []
+        st = []
         for k in range(built.design.n_stages):
-            stages += [k] * built.design.splits[k][i]
-        stage_of_layer.append(tuple(stages))
+            st += [k] * built.design.splits[k][i]
+        stages.append(tuple(st))
     key = seed_key(seed)
-    flat = tuple((K, N, 1) for s in shapes for _, K, N in s)
-    leaves = _normal_leaves(flat, jax.random.fold_in(key, 0))
-    weights, pos = [], 0
-    for s in shapes:
-        weights.append(tuple(leaves[pos:pos + len(s)]))
-        pos += len(s)
-    inputs = list(_normal_leaves(
-        tuple((s[0][0], s[0][1], 0) for s in shapes),
-        jax.random.fold_in(key, 1),
-    ))
-    jax.block_until_ready((weights, inputs))
+    models = [t.get("model", DEFAULT_MODEL) for t in config["tenants"]]
+    made = [None] * len(models)
+    for name in dict.fromkeys(models):
+        idx = [i for i, m in enumerate(models) if m == name]
+        out = load_model(name).build(
+            [config["tenants"][i] for i in idx],
+            key=key, rows=config["rows"], block=block,
+            stages=[stages[i] for i in idx],
+            workloads=[built.workloads[i] for i in idx],
+            max_dim=max_dim,
+        )
+        for i, tenant in zip(idx, out, strict=True):
+            made[i] = tenant
     return Deployment(
         config=config,
         built=built,
         names=[t["name"] for t in config["tenants"]],
         criticality=[t["criticality"] for t in config["tenants"]],
-        shapes=shapes,
-        stage_of_layer=stage_of_layer,
-        weights=weights,
-        inputs=inputs,
+        tenants=made,
         block=block,
     )
 
@@ -251,15 +266,25 @@ def _recording_server_class():
     from repro.pipeline.serve import PharosServer
 
     class RecordingServer(PharosServer):
-        """`PharosServer` that hands every job it is given to
-        ``on_submit`` (the gateway's releases and the client's)."""
+        """`PharosServer` that gives every job it is given (the
+        gateway's releases and the client's) the input its tenant's
+        model gives the job's ordinal, ``k`` for the tenant's ``k``-th
+        job, and hands the job and ``k`` to ``on_submit``."""
 
         on_submit = None
 
+        def __init__(self, tasks, *args, models, **kw):
+            super().__init__(tasks, *args, **kw)
+            self.models = models
+            self.submitted = [0] * len(tasks)
+
         def submit(self, task_id, release=None, *, best_effort=False):
+            k = self.submitted[task_id]
+            self.submitted[task_id] += 1
+            self.inputs[task_id] = self.models[task_id].job_input(k)
             job = super().submit(task_id, release, best_effort=best_effort)
             if self.on_submit is not None:
-                self.on_submit(job)
+                self.on_submit(job, k)
             return job
 
     return RecordingServer
@@ -279,7 +304,8 @@ class JobRecord:
 
 class Tracker:
     """Follows every job through the window: keeps a record of each,
-    the outputs the check samples, and which jobs EDF preempted in the
+    the outputs the check samples with their ordinals (``(k, output)``
+    per tenant), and which jobs EDF preempted in the
     middle of a layer. `poll` runs after every ``step``: a job that a
     step preempted did not run again in that step, so its ``next_tile``
     is where the preemption cut its layer."""
@@ -294,14 +320,15 @@ class Tracker:
         self._due_at: list[dict[float, int]] = [{} for _ in dep.names]
         self.records: list[JobRecord] = []
         self.outputs: dict[int, list] = {i: [] for i in range(len(dep.names))}
+        #: uid -> ordinal among its tenant's jobs, of live jobs
+        self.ordinal: dict[int, int] = {}
         self.midlayer: set[int] = set()
         self.midlayer_kept = 0
         self._seen_pre: dict[int, int] = {}
         self._done = 0
         self._pre = 0
         self._large_kept = [0] * len(dep.names)
-        out_bytes = [s[-1][0] * s[-1][2] * 4 for s in dep.shapes]
-        self._small = [b <= SMALL_OUTPUT_BYTES for b in out_bytes]
+        self._small = [t.output_bytes <= SMALL_OUTPUT_BYTES for t in dep.tenants]
         server.on_submit = self._on_submit
 
     def arm(self, t0: float, schedules: list[list[float]]) -> None:
@@ -310,7 +337,8 @@ class Tracker:
         release time ``t0 + t``."""
         self._due_at = [{t0 + t: k for k, t in enumerate(sch)} for sch in schedules]
 
-    def _on_submit(self, job) -> None:
+    def _on_submit(self, job, ordinal: int) -> None:
+        self.ordinal[job.uid] = ordinal
         k = self._due_at[job.task_id].get(job.release)
         if k is not None:
             self.due[job.uid] = k
@@ -342,12 +370,13 @@ class Tracker:
             j.done_at, self.due.get(j.uid),
         ))
         t = j.task_id
+        k = self.ordinal.pop(j.uid)
         keep = self._small[t]
         if not keep and self._large_kept[t] < MAX_LARGE_KEPT:
             keep = self.rng.random() < 0.5 or mid
             self._large_kept[t] += keep
         if keep:
-            self.outputs[t].append(j.x)
+            self.outputs[t].append((k, self.dep.tenants[t].output(j)))
             self.midlayer_kept += mid
 
     def progress(self) -> dict[int, tuple[int, tuple[int, int]]]:
@@ -390,22 +419,16 @@ def prepare(dep: Deployment, cell: dict, seed: int, horizon_s: float, *,
     from repro.traffic.clock import WallClock
     from repro.traffic.gateway import TrafficGateway
     from repro.traffic.modes import ModeController
-    from repro.pipeline.serve import ServeTask
 
     policy = cell["policy"]
     specs = [cell["tenants"][n] for n in dep.names]
     tasks = [
-        ServeTask(
-            name=n,
-            weights=w,
-            stage_of_layer=st,
+        t.task(
+            n,
             period=s.get("period_s", s.get("contract_period_s")),
             deadline=s.get("deadline_s", 0.0),
-            input_rows=sh[0][0],
         )
-        for n, w, st, s, sh in zip(
-            dep.names, dep.weights, dep.stage_of_layer, specs, dep.shapes
-        )
+        for n, t, s in zip(dep.names, dep.tenants, specs)
     ]
     clock = WallClock()
     recorder = TraceRecorder() if trace else None
@@ -413,8 +436,9 @@ def prepare(dep: Deployment, cell: dict, seed: int, horizon_s: float, *,
     server = _recording_server_class()(
         tasks, n_stages, policy=policy, backend="pallas",
         clock=clock.now, sleep=clock.sleep, trace=recorder,
+        models=dep.tenants,
     )
-    server.inputs = list(dep.inputs)
+    server.inputs = [t.job_input(0) for t in dep.tenants]
     t = time.perf_counter()
     server.warmup()
     t_warm = time.perf_counter() - t
@@ -615,28 +639,28 @@ def _lo_flops(s: Session) -> int:
     total = 0
     for r in tr.records:
         if r.task in lo:
-            total += sum(counts.layer_flops(*sh) for sh in dep.shapes[r.task])
+            t = dep.tenants[r.task]
+            total += t.flops_done((t.layers, 0))
     for j in tr.live:
         if j.task_id in lo:
-            total += counts.flops_done(
-                dep.shapes[j.task_id], dep.block, (j.layer, j.next_tile)
-            )
+            total += dep.tenants[j.task_id].flops_done((j.layer, j.next_tile))
     return total
 
 
 def traced_windows(s: Session, w: Window) -> list[tuple]:
-    """``(M, K, N, start, tiles)`` of every window run while traced."""
+    """``(kernel, flops, bytes, gemm)`` of every window run while traced,
+    from each job's progress (its model's ``windows_between``)."""
     t = w.traced
     if "progress_end" not in t:
         return []
-    dep, wt = s.dep, s.server.window_tiles
+    tenants, wt = s.dep.tenants, s.server.window_tiles
     jobs = dict(t["progress_end"])
     for r in s.tracker.records[t["records"]:t["records_end"]]:
-        jobs[r.uid] = (r.task, (len(dep.shapes[r.task]), 0))
+        jobs[r.uid] = (r.task, (tenants[r.task].layers, 0))
     out = []
     for uid, (task, end) in jobs.items():
         start = t["progress"].get(uid, (task, (0, 0)))[1]
-        out += counts.windows_between(dep.shapes[task], dep.block, wt, start, end)
+        out += tenants[task].windows_between(wt, start, end)
     return out
 
 
@@ -713,14 +737,17 @@ def outcomes(s: Session, w: Window) -> dict:
 
 
 def check(s: Session, limits: dict) -> tuple[bool, dict]:
-    """Compare the sampled outputs with the reference; returns
-    ``(correct, {name: {"value", "limit"}})``."""
+    """Compare each sampled output of ordinal ``k`` with its model's
+    ``reference(k)``; returns ``(correct, {name: {"value", "limit"}})``."""
     dep, tr = s.dep, s.tracker
     checks = {}
     for i, name in enumerate(dep.names):
         outs = tr.outputs[i]
-        ref = reference.chain(dep.inputs[i], dep.weights[i])
-        err = reference.max_rel_err(outs, ref) if outs else math.inf
+        t = dep.tenants[i]
+        err = max(
+            (reference.max_rel_err([out], t.reference(k)) for k, out in outs),
+            default=math.inf,
+        )
         lim = limits["rel_err"][name]
         checks[f"rel_err.{name}"] = _check(err, "<=", lim)
         checks[f"jobs_compared.{name}"] = _check(len(outs), ">=", 1)
@@ -766,7 +793,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     log(f"{time.perf_counter() - t_start:.3f} s to reach the device")
     dep = build_deployment(config, seed, max_dim=max_dim, log=log)
     log(f"built {cell['config']}: {dep.built.design.n_stages} stages, "
-        + ", ".join(f"{n} {len(sh)} layers" for n, sh in zip(dep.names, dep.shapes))
+        + ", ".join(f"{n} {t.layers} layers" for n, t in zip(dep.names, dep.tenants))
         + f"; {time.perf_counter() - t_start:.3f} s since start")
     s = prepare(dep, cell, seed, seconds, trace=trace, log=log)
     for d in s.decisions:
@@ -803,6 +830,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         summary = _summarize(trace_dir, log)
         ctx = _context(s, w, out, summary, peaks, config, cell, cell_name)
+        program_spans.log_longest_calls(ctx.program_spans, w.t0, log)
+        log(f"traced windows: {len(ctx.traced_ops)} from the jobs' progress "
+            f"({len(ctx.traced_windows)} GEMM), {ctx.traced_windows_counted} "
+            "by the server")
     metrics = {}
     if not trace:
         hi = [r * 1e3 for r in out["hi_resp_s"]]
@@ -838,12 +869,17 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = {
             "device_ops": [[n, v] for n, v in summary["device_ops"]],
             "idle_gaps": [[n, v] for n, v in summary["idle_gaps"]],
+            "idle_in_step": summary["idle_in_step"],
         }
     result["checks"] = checks
     return result
 
 
 def _summarize(trace_dir: Path, log):
+    """`trace_reduce.summarize` of the run's profile, with
+    ``idle_in_step``: its idle time charged down to the program's spans
+    (`program_spans.idle_in_step`); None where the profile holds no
+    device operation. The trace is deleted."""
     files = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
     try:
         if not files:
@@ -851,7 +887,9 @@ def _summarize(trace_dir: Path, log):
             return None
         data = trace_reduce.load_xplane(files[-1])
         try:
-            return trace_reduce.summarize(data, SPANS)
+            summary = trace_reduce.summarize(data, SPANS)
+            summary["idle_in_step"] = program_spans.idle_in_step(data, SPANS)
+            return summary
         except ValueError as e:
             log(f"trace: {e}")
             return None
@@ -878,14 +916,26 @@ def _context(s, w, out, summary, peaks, config, cell, cell_name):
                 first.setdefault(key, e.t)
         queue = [first[k] - rel[k] for k in rel if k in first]
     windows = traced_windows(s, w) if summary is not None else []
+    spans = w.report.host_spans or {}
     return SimpleNamespace(
         cell=cell, cell_name=cell_name, config=config, peaks=peaks,
         block=s.dep.block, release_jitter_s=rel_jitter,
         step_busy_s=w.step_busy_s, windows_in_window=w.windows,
         hi_queue_s=queue, hi_resp_s=out["hi_resp_s"],
-        trace=summary, traced_windows=windows,
+        trace=summary,
+        # every window run while traced, as (kernel, flops, bytes)
+        traced_ops=[(k, f, b) for k, f, b, _ in windows],
+        # the GEMM windows among them, as (M, K, N, start, tiles)
+        traced_windows=[g for *_, g in windows if g is not None],
         traced_windows_counted=(
             w.traced.get("windows_end", 0) - w.traced.get("windows", 0)
         ),
-        kernel=KERNEL, percentile=percentile,
+        # the kernel of ``traced_windows``
+        kernel=load_model(DEFAULT_MODEL).KERNEL,
+        # the program's spans over the window (`GatewayReport.host_spans`)
+        program_spans={
+            n: (st.count, st.total_s, st.max_s, st.t_of_max)
+            for n, st in spans.items()
+        },
+        percentile=percentile,
     )

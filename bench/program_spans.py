@@ -1,4 +1,3 @@
-#!/usr/bin/env python3
 """The program's host spans inside ``server.step``: `PharosServer`'s
 four ``pharos.*`` spans (`repro.obs` `TraceRecorder.span`), for the
 ``metrics/server.*_us_per_window.py`` readers and for the breakdown of
@@ -7,30 +6,22 @@ the device's idle time by the innermost span.
 The readers read the span counters of the served window: the gateway
 zeroes them at `begin_run` and copies them as its horizon is reached
 (`GatewayReport.host_spans`), the extent of ``step_busy``. The reader
-context of `harness.run_cell` holds no key for them, so `counters`
-takes them from the window of the run that calls the reader; a context
-that carries ``program_spans`` (``name -> (count, total_s, max_s,
-t_of_max)``) is read first. A program without the spans gives nothing,
-and the readers None.
+context of `harness.run_cell` carries them as ``program_spans``
+(``name -> (count, total_s, max_s, t_of_max)``). A program without the
+spans gives nothing, and the readers None.
 
-Run as a script, it makes one traced run of a cell as ``run.py --trace
-1`` does and prints its result line with one more breakdown,
-``breakdown["idle_in_step"]``: the idle time of the traced window
-charged by `charge_innermost` over the benchmark's spans and
-`PROGRAM_SPANS` (the run is traced whatever ``--trace`` says)::
+A traced run's result line carries ``breakdown["idle_in_step"]``: the
+idle time of the traced window charged by `charge_innermost` over the
+benchmark's spans and `PROGRAM_SPANS` (`idle_in_step`). Run as a
+script, this is ``run.py`` with ``--trace 1``::
 
     python3 bench/program_spans.py --workload <cell> --seed <n> --seconds <s>
 """
 from __future__ import annotations
 
-import sys
-import time
+from collections import defaultdict
 
-T_START = time.perf_counter()
-
-from collections import defaultdict  # noqa: E402
-
-import trace_reduce as tr  # noqa: E402
+import trace_reduce as tr
 
 #: the program's spans, each nested in the benchmark's ``server.step``
 PROGRAM_SPANS = (
@@ -49,26 +40,7 @@ LAUNCH_SPAN = "pharos.window_launch"
 def counters(ctx) -> dict:
     """``name -> (count, total_s, max_s, t_of_max)`` of the program's
     spans over the served window; empty where there are none."""
-    spans = getattr(ctx, "program_spans", None)
-    if spans is not None:
-        return spans
-    w = _run_cell_locals().get("w")
-    stats = getattr(getattr(w, "report", None), "host_spans", None) or {}
-    return {
-        n: (st.count, st.total_s, st.max_s, st.t_of_max)
-        for n, st in stats.items()
-    }
-
-
-def _run_cell_locals() -> dict:
-    """The locals of the `harness.run_cell` that is reading its metrics
-    (its window ``w`` and its ``log``), or {} outside one."""
-    f = sys._getframe(1)
-    while f is not None:
-        if f.f_code.co_name == "run_cell" and "w" in f.f_locals:
-            return f.f_locals
-        f = f.f_back
-    return {}
+    return getattr(ctx, "program_spans", None) or {}
 
 
 def span_us_per_window(ctx, name: str):
@@ -81,17 +53,14 @@ def span_us_per_window(ctx, name: str):
     return spans[name][1] / spans[LAUNCH_SPAN][0] * 1e6
 
 
-def log_longest_calls(ctx) -> None:
-    """Log, in the run's log beside its step stalls, each span's calls,
-    total s, and longest call as (s into the window, s)."""
-    run = _run_cell_locals()
-    w, log = run.get("w"), run.get("log")
-    spans = counters(ctx)
-    if w is None or log is None or not spans:
+def log_longest_calls(spans: dict, t0: float, log) -> None:
+    """Log, beside the run's step stalls, each span's calls, total s,
+    and longest call as (s into the window begun at ``t0``, s)."""
+    if not spans:
         return
     log("program spans in the window (span, calls, total s, s into the "
         "window of the longest call, longest s): " + ", ".join(
-            f"({n}, {c}, {tot:.6f}, {t - w.t0:.3f}, {d:.6f})"
+            f"({n}, {c}, {tot:.6f}, {t - t0:.3f}, {d:.6f})"
             for n, (c, tot, d, t) in sorted(spans.items()) if c))
 
 
@@ -154,58 +123,9 @@ def idle_in_step(trace: dict, span_names, top: int = 10) -> list:
     return [[n, v] for n, v in sorted(s.items(), key=lambda kv: -kv[1])[:top]]
 
 
-def run_traced(workload: str, seed: int, seconds: float, *, t_start: float,
-               peaks: dict, **kw) -> dict:
-    """`harness.run_cell` with ``trace``, its result with
-    ``breakdown["idle_in_step"]`` beside ``idle_gaps`` (where the
-    profile held device operations)."""
-    import harness
-    summarize, got = tr.summarize, {}
-
-    def both(trace, span_names, top=10):
-        out = summarize(trace, span_names, top)
-        got["idle_in_step"] = idle_in_step(trace, span_names, top)
-        return out
-
-    tr.summarize = both
-    try:
-        result = harness.run_cell(
-            workload, seed, seconds, True, t_start=t_start, peaks=peaks, **kw
-        )
-    finally:
-        tr.summarize = summarize
-    if "breakdown" in result and got:
-        result["breakdown"]["idle_in_step"] = got["idle_in_step"]
-        # keep ``checks`` last, as the result line has it
-        result["checks"] = result.pop("checks")
-    return result
-
-
-def main(argv=None) -> int:
-    import json
+if __name__ == "__main__":
+    import sys
 
     import run
 
-    args = run.parse(argv)
-    sys.path.insert(0, str(run.ROOT / "src"))
-    sys.path.insert(0, str(run.BENCH))
-    import harness
-
-    cell = harness.load_spec("workloads", args.workload)
-    chip = run.chip_or_none(int(cell["chips"]))
-    if chip is None:
-        return 1
-    import jax
-    from repro.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    result = run_traced(
-        args.workload, args.seed, args.seconds, t_start=T_START, peaks=chip[1],
-    )
-    print(json.dumps(result), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(run.main([*sys.argv[1:], "--trace", "1"]))

@@ -1,68 +1,13 @@
-"""The plain reference every cell's outputs are compared with.
+"""The comparison that decides whether a served output is correct.
 
-A served job of a tenant is the GEMM chain ``x @ W1 @ ... @ Wn`` in
-fp32, contracted at ``Precision.HIGHEST``: that is what the deployment
-states. The reference computes it layer by layer in ``jax.numpy`` from
-the weights and inputs the benchmark made from the seed; it imports
-nothing of the program.
-
-`chain_bf16x3` is the control: the same chain in the next precision
-down, bf16 with three passes (what ``Precision.HIGH`` runs on the MXU),
-written out so it computes the same on every backend. The split into
-bf16 parts rounds with ``reduce_precision``, which XLA keeps even
-where it may skip a round trip through a narrower type.
+Each tenant's model module (``models/<name>.py``) computes what a job
+must produce, plainly and without the program; this compares a served
+output with it.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-
-def chain(x, weights):
-    """``x @ W1 @ ... @ Wn`` at fp32 ``HIGHEST``."""
-    for w in weights:
-        x = jnp.dot(
-            x, w,
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
-    return x
-
-
-@jax.jit
-def _dot_bf16x3(a, b):
-    def bf16(v):
-        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
-
-    def split(v):
-        hi = bf16(v)
-        return hi.astype(jnp.bfloat16), bf16(v - hi).astype(jnp.bfloat16)
-
-    def dot(p, q):
-        return jnp.dot(p, q, preferred_element_type=jnp.float32)
-
-    a_hi, a_lo = split(a)
-    b_hi, b_lo = split(b)
-    return dot(a_hi, b_hi) + (dot(a_hi, b_lo) + dot(a_lo, b_hi))
-
-
-def chain_bf16x3(x, weights):
-    """The control: the chain with each product in three bf16 passes."""
-    for w in weights:
-        x = _dot_bf16x3(x, w)
-    return x
-
-
-def chain_high(x, weights):
-    """The chain at ``Precision.HIGH`` (three bf16 passes on a TPU; plain
-    fp32 on a CPU), beside `chain_bf16x3` as a witness on the chip."""
-    for w in weights:
-        x = jnp.dot(
-            x, w,
-            precision=jax.lax.Precision.HIGH,
-            preferred_element_type=jnp.float32,
-        )
-    return x
 
 
 @jax.jit

@@ -6,7 +6,10 @@ import types
 import pytest
 
 import counts
+import harness
 import run
+
+gemm = harness.load_model("gemm_chain")
 
 BLOCK = (128, 128, 128)
 
@@ -20,23 +23,23 @@ BLOCK = (128, 128, 128)
 ])
 def test_window_flops_sum_to_the_layer(M, K, N, wt):
     shapes = [(M, K, N)]
-    wins = list(counts.windows_between(shapes, BLOCK, wt, (0, 0), (1, 0)))
+    wins = list(gemm.windows_between(shapes, BLOCK, wt, (0, 0), (1, 0)))
     assert sum(counts.window_flops(k, t, BLOCK) for _, k, _, _, t in wins) \
         == 2 * M * K * N
     assert sum(t for *_, t in wins) == counts.tile_grid(M, K, N, BLOCK)[2]
-    assert counts.flops_done(shapes, BLOCK, (1, 0)) == 2 * M * K * N
+    assert gemm.flops_done(shapes, BLOCK, (1, 0)) == 2 * M * K * N
 
 
 def test_windows_between_follow_a_job_across_layers():
     shapes = [(128, 256, 640), (128, 640, 256), (128, 256, 512)]
     a, b = (0, 3), (2, 0)
-    wins = list(counts.windows_between(shapes, BLOCK, 4, a, b))
+    wins = list(gemm.windows_between(shapes, BLOCK, 4, a, b))
     # layer 0: 5 tiles, window 1, from tile 3; layer 1: 2 tiles, one window
     assert [(w[0:3], w[3], w[4]) for w in wins] == [
         ((128, 256, 640), 3, 1), ((128, 256, 640), 4, 1),
         ((128, 640, 256), 0, 2),
     ]
-    done = counts.flops_done(shapes, BLOCK, b) - counts.flops_done(shapes, BLOCK, a)
+    done = gemm.flops_done(shapes, BLOCK, b) - gemm.flops_done(shapes, BLOCK, a)
     assert done == sum(counts.window_flops(w[1], w[4], BLOCK) for w in wins)
 
 

@@ -119,6 +119,14 @@ def test_every_span_reader_is_in_the_benchmark():
     assert set(READERS.values()) == set(ps.PROGRAM_SPANS)
 
 
+def _traced_run(cell, seed, **kw):
+    return harness.run_cell(
+        cell, seed, 1.0, True, t_start=time.perf_counter(),
+        peaks={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        max_dim=128, **kw,
+    )
+
+
 def test_traced_run_reports_the_span_metrics():
     """A ``--trace 1`` run of a cell in which every step runs a window:
     the four span metrics are read from the run's gateway report, they
@@ -126,14 +134,7 @@ def test_traced_run_reports_the_span_metrics():
     and the log lists each span's longest call. (On the CPU the profiler
     sees no device, so the trace's own metrics stay out of the line.)"""
     lines = []
-    summarize = tr.summarize
-    res = ps.run_traced(
-        "copilot_decode.edf", 2**31 + 4343, 1.0,
-        t_start=time.perf_counter(),
-        peaks={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
-        max_dim=128, log=lines.append,
-    )
-    assert tr.summarize is summarize
+    res = _traced_run("copilot_decode.edf", 2**31 + 4343, log=lines.append)
     got = {m: res["metrics"][m]["value"] for m in READERS}
     assert got["server.launch_us_per_window"] > 0
     assert got["server.acc_init_us_per_window"] > 0
@@ -146,17 +147,14 @@ def test_traced_run_reports_the_span_metrics():
 
 
 def test_idle_in_step_joins_the_breakdown_of_a_profiled_run(monkeypatch):
-    """Where the profile holds device operations, the script's result
-    line carries ``idle_in_step`` beside the harness's breakdown."""
-    def run_cell(*a, **kw):
-        summary = tr.summarize(_trace(), SPANS)
-        return {"breakdown": {"idle_gaps": summary["idle_gaps"]},
-                "checks": {}}
-
-    monkeypatch.setattr(harness, "run_cell", run_cell)
-    res = ps.run_traced("copilot_decode.edf", 1, 1.0, t_start=0.0, peaks={})
+    """Where the profile holds device operations, a traced run's result
+    line carries ``idle_in_step`` beside the harness's breakdown (the
+    run's profile read as the small trace above)."""
+    monkeypatch.setattr(tr, "load_xplane", lambda path: _trace())
+    res = _traced_run("av_stack.edf", 2**31 + 4344, log=lambda m: None)
     assert dict(res["breakdown"]["idle_in_step"]) == {
         "pharos.window_launch": pytest.approx(660e-9),
         "client.idle": pytest.approx(140e-9),
     }
-    assert list(res) == ["breakdown", "checks"]
+    assert list(res["breakdown"]) == ["device_ops", "idle_gaps", "idle_in_step"]
+    assert list(res)[-1] == "checks"
